@@ -47,6 +47,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.configs.cnn_paper import EXTRA_CNNS, PAPER_CNNS  # noqa: E402
 from repro.core import runtime  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.data.pipeline import camera_frame_batch  # noqa: E402
 from repro.engine import (CalibrationConfig, InferenceSession,  # noqa: E402
                           SessionConfig)
@@ -448,6 +449,7 @@ def _persist() -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     print("name,us_per_call,derived,arena_bytes")
     bench_table4_ball()
     bench_table5_pedestrian()

@@ -40,6 +40,7 @@ from repro.configs.cnn_paper import EXTRA_CNNS, PAPER_CNNS  # noqa: E402
 from repro.core import runtime  # noqa: E402
 from repro.data.pipeline import camera_frame_batch  # noqa: E402
 from repro.engine import InferenceSession, SessionConfig  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.serve import (InferenceServer, ServeError,  # noqa: E402
                          ServerConfig, ServerOverloaded)
 
@@ -221,6 +222,7 @@ def main(argv=None) -> None:
     ap.add_argument("--no-persist", action="store_true",
                     help="don't touch BENCH_engine.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     duration = 0.5 if args.quick else 2.0
     print("name,p50_us,derived,qps")

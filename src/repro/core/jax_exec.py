@@ -42,6 +42,10 @@ from .graph import (
 )
 
 _DIMS = ("NHWC", "HWIO", "NHWC")
+# The graphs are float32 models. On the TPU a matmul or conv at default
+# precision multiplies f32 operands in one bf16 pass (~1e-2 relative
+# error, enough to flip argmaxes), so the float paths ask for f32.
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _activation(x: jnp.ndarray, kind: Optional[str], alpha: float) -> jnp.ndarray:
@@ -81,6 +85,7 @@ def _apply(layer, ins: Sequence[jnp.ndarray]) -> jnp.ndarray:
             window_strides=layer.strides,
             padding=((pt, pb), (pl, pr)),
             dimension_numbers=_DIMS,
+            precision=_F32,
         ) + jnp.asarray(layer.bias)
         return _activation(y, layer.activation, layer.alpha)
     if isinstance(layer, DepthwiseConv2D):
@@ -94,10 +99,12 @@ def _apply(layer, ins: Sequence[jnp.ndarray]) -> jnp.ndarray:
             padding=((pt, pb), (pl, pr)),
             dimension_numbers=_DIMS,
             feature_group_count=layer.c_in,
+            precision=_F32,
         ) + jnp.asarray(layer.bias)
         return _activation(y, layer.activation, layer.alpha)
     if isinstance(layer, Dense):
-        y = x.reshape(x.shape[0], -1) @ jnp.asarray(layer.weights)
+        y = jnp.matmul(x.reshape(x.shape[0], -1),
+                       jnp.asarray(layer.weights), precision=_F32)
         y = y + jnp.asarray(layer.bias)
         y = _activation(y, layer.activation, layer.alpha)
         return y.reshape(y.shape[0], 1, 1, -1)
@@ -175,12 +182,25 @@ def make_vmap_forward(graph: CNNGraph):
     return jax.jit(jax.vmap(single))
 
 
+def _runs_as_kernel(layer) -> bool:
+    return isinstance(layer, Conv2D) or (
+        isinstance(layer, MaxPool) and layer.padding == "valid")
+
+
+def pallas_layer_plan(graph: CNNGraph) -> Dict[str, str]:
+    """Per layer, how :func:`forward_pallas` runs it: ``"pallas"`` (one
+    Pallas kernel) or ``"jnp"`` (plain jnp ops)."""
+    return {layer.name: "pallas" if _runs_as_kernel(layer) else "jnp"
+            for layer in graph.layers if not isinstance(layer, Input)}
+
+
 def forward_pallas(graph: CNNGraph, x: jnp.ndarray) -> jnp.ndarray:
     """Run the CNN through the Pallas TPU kernels (conv2d fused with
     bias+activation, maxpool) — the TPU-native deployment path of the
-    generated-C artifact. Interpret-mode on CPU; Mosaic on TPU.
-    Expects an optimized graph (BN folded, activations fused); DAG
-    merges and the non-kernel layers fall back to jnp ops."""
+    generated-C artifact. Mosaic on the TPU, interpret mode on the CPU
+    (see :mod:`repro.kernels.ops`). Expects an optimized graph (BN
+    folded, activations fused); DAG merges and the non-kernel layers
+    fall back to jnp ops (:func:`pallas_layer_plan`)."""
     from repro.kernels import ops
     assert x.ndim == 4
     vals: Dict[str, jnp.ndarray] = {}
@@ -190,7 +210,12 @@ def forward_pallas(graph: CNNGraph, x: jnp.ndarray) -> jnp.ndarray:
             continue
         ins = [vals[n] for n in layer.inputs]
         xi = ins[0]
-        if isinstance(layer, Conv2D):
+        if not _runs_as_kernel(layer):
+            if isinstance(layer, (Dropout, BatchNorm, Dense, Flatten)):
+                raise NotImplementedError(
+                    f"run passes.optimize first ({type(layer).__name__})")
+            y = _apply(layer, ins)
+        elif isinstance(layer, Conv2D):
             act = layer.activation if layer.activation != "softmax" else None
             y = ops.conv2d(xi, jnp.asarray(layer.weights),
                            jnp.asarray(layer.bias), strides=layer.strides,
@@ -198,13 +223,8 @@ def forward_pallas(graph: CNNGraph, x: jnp.ndarray) -> jnp.ndarray:
                            alpha=layer.alpha)
             if layer.activation == "softmax":
                 y = jax.nn.softmax(y, axis=-1)
-        elif isinstance(layer, MaxPool) and layer.padding == "valid":
-            y = ops.maxpool2d(xi, size=layer.size, strides=layer.strides)
-        elif isinstance(layer, (Dropout, BatchNorm, Dense, Flatten)):
-            raise NotImplementedError(
-                f"run passes.optimize first ({type(layer).__name__})")
         else:
-            y = _apply(layer, ins)
+            y = ops.maxpool2d(xi, size=layer.size, strides=layer.strides)
         vals[layer.name] = y
     return vals[graph.sink.name]
 

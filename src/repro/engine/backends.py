@@ -8,8 +8,8 @@ substrates — the paper's deployment artifact plus its two baselines:
 * ``"xla"``    — ``jax.jit`` of the reference forward (the modern
   equivalent of the paper's TF-XLA rival); batches go through a
   ``vmap``'d single-image oracle.
-* ``"pallas"`` — the Pallas TPU kernels (interpret mode on CPU,
-  Mosaic on TPU).
+* ``"pallas"`` — the Pallas TPU kernels (Mosaic on the TPU, interpret
+  mode on the CPU backend; any other platform raises).
 
 ``Backend`` is a formal ABC, not duck typing: every substrate
 implements ``predict_batch`` and inherits ``describe()`` (a stable
@@ -296,7 +296,8 @@ class QuantizedXLABackend(_JaxBackend):
 
 @register_backend("pallas")
 class PallasBackend(_JaxBackend):
-    """TPU-native deployment path (interpret mode off-TPU). Requires an
+    """TPU-native deployment path: Mosaic kernels on the TPU, interpret
+    mode on the CPU backend, an error on any other platform. Requires an
     optimized graph — BN folded, activations fused, no Dense/Flatten."""
 
     def _make_fn(self, graph: CNNGraph):
@@ -307,6 +308,19 @@ class PallasBackend(_JaxBackend):
             return jax_exec.forward_pallas(graph, x)
 
         return f
+
+    def describe(self) -> dict:
+        """Adds the device the kernels run on, whether they run in
+        interpret mode, and per layer ``"pallas"`` or ``"jnp"``."""
+        import jax
+
+        from repro.kernels import ops
+        d = super().describe()
+        dev = jax.devices()[0]
+        d.update(platform=dev.platform, device_kind=dev.device_kind,
+                 interpret=ops._default_interpret(),
+                 layers=jax_exec.pallas_layer_plan(self.graph))
+        return d
 
 
 # =========================================================== LM workload ====

@@ -105,7 +105,7 @@ class LMConfig:
     persisted in the tuning cache) and fall back to the defaults
     otherwise.  ``mesh_shape`` requests a device mesh for data-parallel
     prefill via :mod:`repro.launch.mesh`; when the host has fewer
-    devices the session falls back to single-device cleanly.
+    devices the session raises ``ValueError``.
     """
 
     arch: str = "gemma3-4b"

@@ -13,12 +13,11 @@ backend registry (the ``"pallas-lm"`` entry), and the on-disk
 exactly like C unroll levels; see
 :func:`repro.engine.autotune.tune_lm_variants`).  A config with
 ``lm.mesh_shape`` set serves data-parallel prefill through
-:class:`repro.launch.sharding.MeshPar`, falling back cleanly to
-single-device when the host has fewer devices (the CPU CI path).
+:class:`repro.launch.sharding.MeshPar`; a host with fewer devices than
+the mesh needs is an error, never a silent single-device run.
 """
 from __future__ import annotations
 
-import warnings
 from typing import Optional
 
 import numpy as np
@@ -41,7 +40,8 @@ class LMSession:
     params:  optional parameter pytree (defaults to a seeded
              ``init_params`` of the arch — the deterministic CI path).
     mesh:    optional pre-built jax mesh; otherwise ``lm.mesh_shape``
-             (when set and satisfiable on this host) builds one.
+             (when set) builds one, and raises ``ValueError`` when the
+             host has too few devices.
     """
 
     def __init__(self, config=None, *, params=None, mesh=None):
@@ -120,8 +120,8 @@ class LMSession:
 
     @staticmethod
     def _make_mesh(shape):
-        """Build the requested mesh, or fall back to single-device when
-        the host cannot satisfy it (CPU CI has one device)."""
+        """Build the requested mesh; raise when the host has too few
+        devices for it."""
         import math
 
         import jax
@@ -130,11 +130,9 @@ class LMSession:
         need = math.prod(shape)
         have = len(jax.devices())
         if need > have:
-            warnings.warn(
+            raise ValueError(
                 f"lm.mesh_shape {tuple(shape)} needs {need} devices but "
-                f"the host has {have}; falling back to single-device",
-                RuntimeWarning, stacklevel=3)
-            return None
+                f"the host has {have}")
         return make_mesh(shape)
 
     def _tuning_cache(self) -> TuningCache:

@@ -26,6 +26,13 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# The whole padded image tile, its double buffer and the f32 (HIGHEST)
+# split of each tap window stay in VMEM: the robot net's 60x80 first
+# layer needs more than the 16 MiB default scoped limit of a v5e, whose
+# VMEM holds 128 MiB.
+_VMEM_LIMIT_BYTES = 32 * 2**20
 
 
 def _conv_kernel(x_ref, w_ref, b_ref, o_ref, *, kh: int, kw: int,
@@ -33,16 +40,17 @@ def _conv_kernel(x_ref, w_ref, b_ref, o_ref, *, kh: int, kw: int,
                  act: Optional[str], alpha: float):
     ci = x_ref.shape[-1]
     tc = o_ref.shape[-1]
-    x = x_ref[0]  # (HP, WP, CI) — whole padded tile, VMEM-resident
     acc = jnp.zeros((oh * ow, tc), jnp.float32)
     for n in range(kh):          # P1: static tap loop, unrolled at trace
         for m in range(kw):
-            xs = jax.lax.slice(
-                x, (n, m, 0),
-                (n + (oh - 1) * sh + 1, m + (ow - 1) * sw + 1, ci),
-                (sh, sw, 1))  # (OH, OW, CI)
+            # (OH, OW, CI) tap window, read from the VMEM-resident padded
+            # tile with a strided Ref load (Mosaic refuses a strided
+            # slice of a loaded value)
+            xs = x_ref[0, pl.ds(n, oh, stride=sh), pl.ds(m, ow, stride=sw), :]
             acc += jnp.dot(xs.reshape(oh * ow, ci),
-                           w_ref[n, m].astype(x.dtype),
+                           w_ref[n, m].astype(xs.dtype),
+                           # f32 operands in full f32, not one bf16 pass
+                           precision=jax.lax.Precision.HIGHEST,
                            preferred_element_type=jnp.float32)
     acc = acc + b_ref[0][None, :].astype(jnp.float32)
     if act == "relu":
@@ -88,5 +96,7 @@ def conv2d_pallas(x: jax.Array, w: jax.Array, b: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((1, oh, ow, tc), lambda i, j: (i, 0, 0, j)),
         out_shape=jax.ShapeDtypeStruct((n, oh, ow, co), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(x, w, b2)

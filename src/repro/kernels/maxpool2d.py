@@ -15,15 +15,12 @@ from jax.experimental import pallas as pl
 
 
 def _pool_kernel(x_ref, o_ref, *, kh, kw, sh, sw, oh, ow):
-    x = x_ref[0]  # (H, W, TC)
-    tc = x.shape[-1]
     out = None
     for n in range(kh):
         for m in range(kw):
-            xs = jax.lax.slice(
-                x, (n, m, 0),
-                (n + (oh - 1) * sh + 1, m + (ow - 1) * sw + 1, tc),
-                (sh, sw, 1))
+            # strided load from the Ref: Mosaic refuses a strided slice
+            # of a loaded value (vector.extract_strided_slice)
+            xs = x_ref[0, pl.ds(n, oh, stride=sh), pl.ds(m, ow, stride=sw), :]
             out = xs if out is None else jnp.maximum(out, xs)
     o_ref[0] = out
 

@@ -1,12 +1,12 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernels target TPU and are validated in interpret mode per the repo
-policy). On a real TPU backend the same calls compile to Mosaic.
+The kernels target the TPU, where each call compiles to Mosaic. On the
+CPU backend (``JAX_PLATFORMS=cpu``, where the tests run) they run in
+Pallas interpret mode. Any other platform raises: a kernel never runs
+interpreted where a device was expected.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
 import jax
@@ -18,39 +18,53 @@ from .maxpool2d import maxpool2d_pallas
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret on the CPU backend, compile on the TPU, raise elsewhere."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(
+        f"the Pallas kernels target the TPU; JAX's default backend is "
+        f"{platform!r} (use JAX_PLATFORMS=cpu for interpret mode)")
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "strides", "padding", "act", "alpha", "block_cout"))
+# ``interpret`` is a static argument of each jitted kernel, so a trace
+# made in one mode is never reused in the other
+_conv2d = jax.jit(conv2d_pallas, static_argnames=(
+    "strides", "padding", "act", "alpha", "block_cout", "interpret"))
+_maxpool2d = jax.jit(maxpool2d_pallas, static_argnames=(
+    "size", "strides", "block_c", "interpret"))
+_flash_attention = jax.jit(flash_attention_pallas, static_argnames=(
+    "causal", "window", "scale", "block_q", "block_k", "interpret"))
+_linear_scan = jax.jit(linear_scan_pallas,
+                       static_argnames=("chunk", "interpret"))
+
+
 def conv2d(x, w, b, *, strides: Tuple[int, int] = (1, 1),
            padding: str = "valid", act: Optional[str] = None,
            alpha: float = 0.1, block_cout: Optional[int] = None):
-    return conv2d_pallas(x, w, b, strides=strides, padding=padding, act=act,
-                         alpha=alpha, block_cout=block_cout,
-                         interpret=_default_interpret())
+    return _conv2d(x, w, b, strides=strides, padding=padding, act=act,
+                   alpha=alpha, block_cout=block_cout,
+                   interpret=_default_interpret())
 
 
-@functools.partial(jax.jit, static_argnames=("size", "strides", "block_c"))
 def maxpool2d(x, *, size: Tuple[int, int] = (2, 2),
               strides: Optional[Tuple[int, int]] = None,
               block_c: Optional[int] = None):
-    return maxpool2d_pallas(x, size=size, strides=strides, block_c=block_c,
-                            interpret=_default_interpret())
+    return _maxpool2d(x, size=size, strides=strides, block_c=block_c,
+                      interpret=_default_interpret())
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "scale", "block_q", "block_k"))
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128):
-    return flash_attention_pallas(
+    return _flash_attention(
         q, k, v, causal=causal, window=window, scale=scale,
         block_q=block_q, block_k=block_k, interpret=_default_interpret())
 
 
-@functools.partial(jax.jit, static_argnames=("chunk",))
 def linear_scan(decay, k, v, r, s0, *, chunk: int = 128):
-    return linear_scan_pallas(decay, k, v, r, s0, chunk=chunk,
-                              interpret=_default_interpret())
+    return _linear_scan(decay, k, v, r, s0, chunk=chunk,
+                        interpret=_default_interpret())

@@ -10,12 +10,20 @@ from __future__ import annotations
 from typing import Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # MeshPar's sharding rules are written for Auto axes (GSPMD
+    # propagation); jax.make_mesh defaults to Explicit
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(shape: Sequence[int], axes: Optional[Sequence[str]] = None):
@@ -24,7 +32,7 @@ def make_mesh(shape: Sequence[int], axes: Optional[Sequence[str]] = None):
     if axes is None:
         axes = {2: ("data", "model"),
                 3: ("pod", "data", "model")}[len(shape)]
-    return jax.make_mesh(shape, tuple(axes))
+    return _auto_mesh(shape, tuple(axes))
 
 
 def dp_axes(mesh) -> Tuple[str, ...]:

@@ -21,7 +21,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.models.config import ModelConfig
@@ -257,8 +256,8 @@ class MeshPar(Par):
         in_specs = (P(dp, None, None), _moe_local_specs(p))
         out_spec = P(dp, None, None)
 
-        @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_spec, check_rep=False)
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_spec, check_vma=False)
         def _moe(x_local, p_local):
             b, t, d = x_local.shape
             y = moe_mlp(x_local.reshape(b * t, d), p_local,
@@ -299,9 +298,9 @@ class MeshPar(Par):
         w_specs = jax.tree.map(lambda l: P(*([None] * l.ndim)), p)
 
         @functools.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(P(dp, "model", None), w_specs, P(dp, None)),
-            out_specs=P(dp, "model", None), check_rep=False)
+            out_specs=P(dp, "model", None), check_vma=False)
         def _attn(x_loc, w, pos_loc):
             b, t_loc, _ = x_loc.shape
 
@@ -356,9 +355,9 @@ class MeshPar(Par):
             return P(*([None] * l.ndim))               # router/shared: repl
         p_specs = jax.tree_util.tree_map_with_path(pspec, p)
 
-        @functools.partial(shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=(P(dp, "model", None), p_specs),
-                           out_specs=P(dp, "model", None), check_rep=False)
+                           out_specs=P(dp, "model", None), check_vma=False)
         def _moe(x_local, p_local):
             b, t, d = x_local.shape
             y = moe_mlp_ep(x_local.reshape(b * t, d), p_local,

@@ -141,3 +141,18 @@ def test_linear_scan_state_carry():
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(s_full), np.asarray(s2),
                                rtol=1e-5, atol=1e-5)
+
+
+# ------------------------------------------------------- interpret policy ----
+
+@pytest.mark.parametrize("platform,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None)])
+def test_interpret_only_on_cpu(platform, interpret, monkeypatch):
+    """Interpret mode on the CPU backend, Mosaic on the TPU, and an error
+    on any other platform rather than a silent interpreted run."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: platform)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="target the TPU"):
+            ops._default_interpret()
+    else:
+        assert ops._default_interpret() is interpret

@@ -224,10 +224,8 @@ def test_mesh_fallback_on_undersized_host():
     cfg = SessionConfig(backend="pallas-lm",
                         lm=LMConfig(max_context=16, mesh_shape=(8, 8),
                                     attn_variant="flash_jax"))
-    with pytest.warns(RuntimeWarning, match="mesh_shape"):
-        s = LMSession(config=cfg)
-    assert s.mesh is None
-    assert s.generate(_prompts(t=8), 2).shape == (BATCH, 2)
+    with pytest.raises(ValueError, match="mesh_shape"):
+        LMSession(config=cfg)
 
 
 def test_mesh_single_device_matches_unmeshed():

@@ -16,3 +16,18 @@ def test_pallas_path_matches_oracle(name):
     ref = np.asarray(jax_exec.forward(g, jnp.asarray(x)))
     got = np.asarray(jax_exec.forward_pallas(g, jnp.asarray(x)))
     np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-5)
+
+
+def test_pallas_describe_reports_device_and_layers():
+    from repro.configs.cnn_paper import residual_cnn
+    from repro.engine import InferenceSession, SessionConfig
+    sess = InferenceSession(residual_cnn(),
+                            config=SessionConfig(backend="pallas"))
+    d = sess.backend.describe()
+    assert (d["platform"], d["interpret"]) == ("cpu", True)
+    assert d["device_kind"]
+    # convs run as kernels; the depthwise conv, Add, Concat and pools
+    # fall back to jnp ops
+    assert d["layers"]["stem"] == d["layers"]["head"] == "pallas"
+    assert d["layers"]["dw"] == d["layers"]["mix"] == "jnp"
+    assert set(d["layers"]) == {l.name for l in sess.graph.layers[1:]}
