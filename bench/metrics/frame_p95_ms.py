@@ -1,0 +1,10 @@
+"""95th percentile of the time of every ``predict`` call in the window
+(closed loop)."""
+from bench.traffic import quantile
+
+
+def read(ctx):
+    r = ctx.record
+    if r["loop"] != "closed" or not r["durations_s"]:
+        return None
+    return 1e3 * quantile(r["durations_s"], 0.95)
