@@ -4,7 +4,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs.cnn_paper import EXTRA_CNNS, PAPER_CNNS
+from repro.core.graph import Conv2D
 from repro.kernels import ops, ref
+from repro.kernels.conv2d import conv_tap_groups
 
 jax.config.update("jax_enable_x64", False)
 
@@ -24,6 +27,12 @@ def rnd(key, shape, dtype=jnp.float32):
     (2, 6, 6, 4, 16, 2, 2, 2, "valid", "relu"),
     (1, 12, 10, 2, 6, 1, 1, 1, "valid", None),
     (1, 60, 80, 3, 8, 3, 3, 1, "same", "leaky_relu"),  # robot detector L1
+    # across the tap-group boundaries of conv_tap_groups
+    (1, 7, 9, 16, 20, 3, 3, 1, "same", "leaky_relu"),  # 2 groups: K 128+16
+    (1, 9, 4, 32, 64, 3, 3, 1, "same", "relu"),  # 3 groups
+    (2, 4, 2, 64, 2, 4, 2, 1, "valid", None),  # pedestrian's last, 4 groups
+    (1, 6, 5, 128, 8, 3, 3, 1, "valid", None),  # g = 1: one dot per tap
+    (2, 20, 14, 1, 8, 5, 5, 2, "same", "relu"),  # ball L1, non-square
 ])
 def test_conv2d(n, h, w, ci, co, kh, kw, stride, padding, act, dtype):
     x = rnd(0, (n, h, w, ci), dtype)
@@ -47,6 +56,29 @@ def test_conv2d_blocked_cout():
     y2 = ref.conv2d_ref(x, wt, b, padding="same")
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=1e-5,
                                atol=1e-5)
+
+
+def test_conv_tap_groups_of_the_nets():
+    """One dot per grid step for every layer with c_in <= 14; the groups
+    cover the taps in order and fill at most 128 lanes of K."""
+    groups = {
+        name: [conv_tap_groups(kh, kw, ci)
+               for kh, kw, ci, _ in (l.weights.shape for l in make(0).layers
+                                     if isinstance(l, Conv2D))]
+        for name, make in {**PAPER_CNNS, **EXTRA_CNNS}.items()}
+    assert groups == {
+        "robot": [(9,), (9,), (9,), (9,), (8, 1)],
+        "pedestrian": [(9,), (9,), (4, 4, 1), (2, 2, 2, 2)],
+        "ball": [(25,), (9,), (4,)],
+        "residual": [(9,), (1,), (1,), (9,), (1,)],
+    }
+    assert conv_tap_groups(1, 1, 8) == (1,)
+    assert conv_tap_groups(3, 3, 128) == (1,) * 9
+    assert conv_tap_groups(3, 3, 300) == (1,) * 9
+    for kh, kw, ci in [(3, 3, 20), (5, 5, 6), (7, 1, 100), (2, 3, 43)]:
+        gs = conv_tap_groups(kh, kw, ci)
+        assert sum(gs) == kh * kw and max(gs) * ci <= 128
+        assert all(g == gs[0] for g in gs[:-1]) and gs[-1] <= gs[0]
 
 
 # ------------------------------------------------------------- maxpool2d ----
